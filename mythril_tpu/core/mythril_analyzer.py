@@ -15,6 +15,7 @@ from mythril_tpu.analysis.report import Issue, Report
 from mythril_tpu.analysis.security import fire_lasers, retrieve_callback_issues
 from mythril_tpu.analysis.symbolic import SymExecWrapper
 from mythril_tpu.ethereum.evmcontract import EVMContract
+from mythril_tpu.exceptions import DeviceWarmupError
 from mythril_tpu.laser.evm.iprof import InstructionProfiler
 from mythril_tpu.support.source_support import Source
 from mythril_tpu.support.start_time import StartTime
@@ -165,6 +166,12 @@ class MythrilAnalyzer:
         except KeyboardInterrupt:
             log.critical("Keyboard Interrupt")
             return retrieve_callback_issues(modules)
+        except DeviceWarmupError:
+            # no salvage: a tpu-batch run without its device is an error,
+            # and what the callback modules found so far must not leak
+            # into the next analysis in this process
+            retrieve_callback_issues(modules)
+            raise
         except Exception:
             log.critical(
                 "Exception occurred, aborting analysis. Please report this issue.\n"

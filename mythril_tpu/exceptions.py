@@ -21,6 +21,11 @@ class CriticalError(MythrilTpuBaseException):
     """A critical, user-facing error."""
 
 
+class DeviceWarmupError(CriticalError):
+    """The device kernels of a tpu-batch analysis failed to compile or
+    run on the accelerator, so the analysis cannot use the device."""
+
+
 class AddressNotFoundError(MythrilTpuBaseException):
     """The address was not found."""
 

@@ -74,6 +74,42 @@ def _myth_argv() -> List[str]:
     return [sys.executable, os.path.join(root, "myth")]
 
 
+def device_slots(n_workers: int) -> List[Optional[int]]:
+    """The chip each of ``n_workers`` worker processes on this host gets.
+
+    A process that initializes JAX on an accelerator takes every chip it
+    can see, so a second one would get none. Each worker is therefore
+    given its own chip, and asking for more workers than the host has
+    chips is refused. Workers held to the CPU (JAX_PLATFORMS=cpu) need
+    no chip: their slots are None."""
+    from mythril_tpu.support.accelerators import host_chip_count, runs_on_cpu
+
+    if runs_on_cpu():
+        return [None] * n_workers
+    chips = host_chip_count()
+    if n_workers > chips:
+        raise ValueError(
+            "%d device workers requested but this host has %d accelerator "
+            "chip(s); each worker needs a chip of its own (run workers "
+            "with JAX_PLATFORMS=cpu to use the CPU)" % (n_workers, chips)
+        )
+    return list(range(n_workers))
+
+
+def chip_env(chip: int) -> Dict[str, str]:
+    """Environment that confines a process to one TPU chip of the host,
+    with a runtime port of its own so processes on one host do not
+    collide."""
+    port = str(8476 + chip)
+    return {
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": port,
+        "TPU_PROCESS_ADDRESSES": "localhost:" + port,
+    }
+
+
 def spawn_worker(
     socket_path: str,
     store_dir: Optional[str] = None,
@@ -83,8 +119,10 @@ def spawn_worker(
     lanes: Optional[int] = None,
     env: Optional[Dict[str, str]] = None,
     stderr=None,
+    chip: Optional[int] = None,
 ) -> subprocess.Popen:
-    """Launch one fleet worker process (``myth serve --socket ...``)."""
+    """Launch one fleet worker process (``myth serve --socket ...``),
+    confined to ``chip`` when one is given (see device_slots)."""
     argv = _myth_argv() + [
         "serve",
         "--socket", socket_path,
@@ -98,6 +136,8 @@ def spawn_worker(
     if lanes:
         argv += ["--lanes", str(lanes)]
     child_env = dict(os.environ)
+    if chip is not None:
+        child_env.update(chip_env(chip))
     if env:
         child_env.update(env)
     return subprocess.Popen(argv, env=child_env, stderr=stderr)

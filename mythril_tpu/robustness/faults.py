@@ -48,9 +48,11 @@ Fault kinds and the exceptions they raise (every instance carries
   ``crash``         :class:`InjectedCrash` — unexpected worker exception
 """
 
+import contextlib
 import logging
 import os
 import random
+import threading
 import zlib
 from typing import Dict, List, Optional
 
@@ -99,7 +101,7 @@ class DeviceRuntimeFault(InjectedFault):
 
 
 class InjectedTimeout(InjectedFault):
-    """Injected timeout (hung tunnel / hung solve)."""
+    """Injected timeout (hung device call / hung solve)."""
 
 
 class WorkerDeath(InjectedFault):
@@ -250,6 +252,9 @@ class FaultPlan:
 # so importing this module never reads the environment eagerly
 _STATE: List[Optional[FaultPlan]] = []
 
+# per-thread: crossings inside suppressed() neither fire nor count a hit
+_QUIET = threading.local()
+
 
 def _load() -> Optional[FaultPlan]:
     if not _STATE:
@@ -283,6 +288,18 @@ def active() -> Optional[FaultPlan]:
     return _load()
 
 
+@contextlib.contextmanager
+def suppressed():
+    """Cross seams unarmed on this thread (device warmup: the compile
+    that the guarded seams presuppose is not one of them). Other threads
+    keep firing."""
+    _QUIET.on = True
+    try:
+        yield
+    finally:
+        _QUIET.on = False
+
+
 def fire(seam: str, context: Optional[str] = None) -> None:
     """Cross a seam: raise the planned fault if one is armed for it.
 
@@ -291,7 +308,7 @@ def fire(seam: str, context: Optional[str] = None) -> None:
     spec's ``match=`` option filters on.
     """
     plan = _STATE[0] if _STATE else _load()
-    if plan is None:
+    if plan is None or getattr(_QUIET, "on", False):
         return
     exc = plan.maybe(seam, context)
     if exc is not None:

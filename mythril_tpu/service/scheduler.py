@@ -45,6 +45,7 @@ from enum import Enum
 from typing import Dict, List, Optional
 
 from mythril_tpu import obs
+from mythril_tpu.exceptions import DeviceWarmupError
 from mythril_tpu.obs import catalog as _obs_catalog
 from mythril_tpu.robustness import faults
 from mythril_tpu.robustness.checkpoint import CheckpointJournal
@@ -681,7 +682,9 @@ class AnalysisService:
                     laser_box[0], "executed_transaction_rounds", 0
                 )
             outcome["error"] = str(e)
-            outcome["crashed"] = True
+            # a device that failed to warm up is no fault of the
+            # contract: fail the job without a retry or a strike
+            outcome["crashed"] = not isinstance(e, DeviceWarmupError)
             outcome["report"] = {
                 "exception": type(e).__name__,
                 "seam": getattr(e, "seam", None),

@@ -154,7 +154,7 @@ def test_swc101_integer_overflow_parity():
     assert strategy.device_rounds > 0
 
 
-def test_swc105_ether_thief_parity():
+def test_swc105_ether_thief_parity(generous_solver_budget):
     issues, strategy = analyze_tpu(
         """
         PUSH1 0x00
@@ -169,7 +169,7 @@ def test_swc105_ether_thief_parity():
         POP
         STOP
         """,
-        timeout=90,
+        timeout=900,
     )
     assert "105" in swc_ids(issues)
 

@@ -119,3 +119,26 @@ def test_fleet_stats_aggregate_two_workers(fleet):
     assert set(stats["workers"]) == {"w0", "w1"}
     assert all(s is not None for s in stats["workers"].values())
     assert stats["gateway"]["workers_alive"] == 2
+
+
+def test_device_workers_get_a_chip_each_and_no_more(monkeypatch, capsys):
+    """One host, one chip per worker process: a process that starts JAX
+    on an accelerator takes every chip it sees, so workers are confined
+    to a chip each and the gateway refuses more workers than chips."""
+    from mythril_tpu.fleet.worker import chip_env, device_slots
+    from mythril_tpu.support import accelerators
+    from mythril_tpu.interfaces import cli
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert device_slots(3) == [None, None, None]
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setattr(accelerators, "host_chip_count", lambda: 1)
+    assert device_slots(1) == [0]
+    assert chip_env(0)["TPU_VISIBLE_CHIPS"] == "0"
+    with pytest.raises(ValueError, match="1 accelerator chip"):
+        device_slots(2)
+    with pytest.raises(SystemExit) as exit_info:
+        cli.main(["gateway", "--spawn", "2", "--listen", "127.0.0.1:0"])
+    assert exit_info.value.code == 1
+    assert "2 device workers requested" in capsys.readouterr().err

@@ -38,10 +38,13 @@ def deploy(runtime_src: str):
         ).hex()
         + runtime
     )
+    # the creation budget defaults to 10 s, which a heavily loaded
+    # machine can spend on this short creation tx, leaving no open state
     laser = LaserEVM(
         strategy=BreadthFirstSearchStrategy,
         transaction_count=1,
         execution_timeout=60,
+        create_timeout=600,
         max_depth=128,
     )
     laser.sym_exec(creation_code=creation, contract_name="T")

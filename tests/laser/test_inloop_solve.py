@@ -276,8 +276,9 @@ def _analyze(src, monkeypatch, inloop: bool, tx=1, timeout=480):
     # small always-engage config: the production default defers the
     # device 1.5 s, which a tiny test contract never reaches
     monkeypatch.setattr(backend, "DEFAULT_BATCH_CFG", MESH_CFG)
-    backend._warmup_events.pop((MESH_CFG, False), None)
-    backend._warmup_done.discard((MESH_CFG, False))
+    key = backend._warmup_key(MESH_CFG, False)
+    backend._warmup_events.pop(key, None)
+    backend._warmup_done.discard(key)
     monkeypatch.setenv("MYTHRIL_TPU_INLOOP_SOLVE", "1" if inloop else "0")
     GLOBAL.reset()
     runtime = assemble(src).hex()

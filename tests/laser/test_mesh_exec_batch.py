@@ -31,8 +31,9 @@ def mesh_on(monkeypatch):
     monkeypatch.setattr(backend, "DEFAULT_BATCH_CFG", MESH_CFG)
     # the sharded kernel is a different executable than the single-device
     # one: force a fresh warmup for this config under mesh mode
-    backend._warmup_events.pop((MESH_CFG, False), None)
-    backend._warmup_done.discard((MESH_CFG, False))
+    key = backend._warmup_key(MESH_CFG, False)
+    backend._warmup_events.pop(key, None)
+    backend._warmup_done.discard(key)
 
 
 def make_creation(runtime_hex: str) -> str:

@@ -32,9 +32,12 @@ def signature(result):
     )
 
 
-def run_service(spec, submissions, timeout=120):
+def run_service(spec, submissions, timeout=900):
     """One service run under ``spec``; returns {name: (status, result)}.
-    Faults arm AFTER construction so service startup stays clean."""
+    Faults arm AFTER construction so service startup stays clean. The
+    job budget is far above what these analyses need to drain, so the
+    clean and faulted runs compare complete explorations, not whatever
+    a loaded machine got through before a deadline."""
     service = AnalysisService(workers=2, batch_cfg=TEST_CFG, gather_window_s=0.5)
     faults.configure(spec)
     out = {}
@@ -44,7 +47,7 @@ def run_service(spec, submissions, timeout=120):
             for name, (r, c) in submissions.items()
         }
         for name, job_id in ids.items():
-            assert service.wait(job_id, 300), name
+            assert service.wait(job_id, timeout), name
             out[name] = (service.status(job_id), service.result(job_id))
         out["__stats__"] = service.stats()
     finally:
@@ -71,7 +74,9 @@ ALL_SEAMS_SPEC = (
 )
 
 
-def test_service_run_with_faults_at_every_seam_matches_clean():
+def test_service_run_with_faults_at_every_seam_matches_clean(
+    generous_solver_budget,
+):
     backend.warmup_device(TEST_CFG)
     submissions = {
         "suicidal": contract_pair(SUICIDE_SRC),
@@ -144,7 +149,9 @@ def test_poison_job_quarantined_others_unaffected():
         service.shutdown(wait=True, timeout=30)
 
 
-def test_breaker_opens_under_persistent_device_failure_jobs_complete():
+def test_breaker_opens_under_persistent_device_failure_jobs_complete(
+    generous_solver_budget,
+):
     """Ladder step 3 end-to-end: every device round fails, the breaker
     opens, and the jobs still complete HOST-ONLY with the clean issue
     sets and degraded=true."""

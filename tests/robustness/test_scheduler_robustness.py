@@ -137,6 +137,29 @@ def test_poison_job_fails_with_report_worker_survives(stub_pipeline, service):
     assert service.status(again)["state"] == "done"
 
 
+def test_device_warmup_failure_fails_job_without_strike(
+    stub_pipeline, service, monkeypatch
+):
+    """A job whose device kernels cannot warm up fails with that error.
+    The contract is not at fault: no retry, and no strike toward
+    quarantine."""
+    import mythril_tpu.analysis.symbolic as symbolic
+    from mythril_tpu.exceptions import DeviceWarmupError
+
+    def no_device(*args, **kw):
+        raise DeviceWarmupError("device warmup failed on tpu: compile error")
+
+    monkeypatch.setattr(symbolic, "SymExecWrapper", no_device)
+    job = service.submit("600d", tx_count=1, name="needs-device")
+    assert service.wait(job, 20)
+    status = service.status(job)
+    assert status["state"] == "failed"
+    assert "device warmup failed" in status["error"]
+    assert not status["retried"]
+    assert not service.cache.is_quarantined(cache_key("", "600d"))
+    assert service.stats()["jobs_failed"] == 1
+
+
 def test_transient_crash_retries_once_and_clears_strikes(
     stub_pipeline, service
 ):

@@ -81,7 +81,7 @@ def contract_pair(src):
     return runtime, creation
 
 
-def test_coresident_jobs_share_batch_and_split_findings():
+def test_coresident_jobs_share_batch_and_split_findings(generous_solver_budget):
     """The tentpole acceptance path: two concurrent jobs must land in
     the SAME device batch (witnessed on the job_id plane census), their
     findings must split exactly per job, and a resubmission must answer
@@ -89,12 +89,16 @@ def test_coresident_jobs_share_batch_and_split_findings():
     backend.warmup_device(TEST_CFG)
     r1, c1 = contract_pair(SUICIDE_SRC)
     r2, c2 = contract_pair(ORIGIN_SRC)
-    service = AnalysisService(workers=2, batch_cfg=TEST_CFG, gather_window_s=1.0)
+    # the window a round waits for the other job's frontier: a round
+    # stops waiting as soon as every active job is pending, so a wide
+    # window only costs time when the other job has no round left, and
+    # a narrow one lets a loaded machine run the two jobs apart
+    service = AnalysisService(workers=2, batch_cfg=TEST_CFG, gather_window_s=10.0)
     try:
         t0 = time.time()
-        j1 = service.submit(r1, c1, tx_count=1, timeout=120, name="suicidal")
-        j2 = service.submit(r2, c2, tx_count=1, timeout=120, name="tx-origin")
-        assert service.wait(j1, 300) and service.wait(j2, 300)
+        j1 = service.submit(r1, c1, tx_count=1, timeout=900, name="suicidal")
+        j2 = service.submit(r2, c2, tx_count=1, timeout=900, name="tx-origin")
+        assert service.wait(j1, 900) and service.wait(j2, 900)
         cold_wall = time.time() - t0
         res1, res2 = service.result(j1), service.result(j2)
         assert service.status(j1)["state"] == "done", service.status(j1)
@@ -115,7 +119,7 @@ def test_coresident_jobs_share_batch_and_split_findings():
 
         # warm resubmission: < 1% of cold wall, identical findings
         t0 = time.time()
-        j3 = service.submit(r1, c1, tx_count=1, timeout=120, name="suicidal")
+        j3 = service.submit(r1, c1, tx_count=1, timeout=900, name="suicidal")
         assert service.wait(j3, 30)
         warm_wall = time.time() - t0
         assert service.status(j3)["cache_hit"]
