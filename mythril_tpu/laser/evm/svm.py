@@ -17,6 +17,7 @@ from copy import copy
 from datetime import datetime, timedelta
 from typing import Callable, DefaultDict, Dict, List, Optional, Tuple
 
+from mythril_tpu import obs
 from mythril_tpu.laser.evm.cfg import Edge, JumpType, Node, NodeFlags
 from mythril_tpu.laser.evm.evm_exceptions import StackUnderflowException, VmException
 from mythril_tpu.laser.evm.instructions import Instruction
@@ -465,9 +466,12 @@ class LaserEVM:
                     check_potential_issues,
                 )
 
-                check_potential_issues(global_state)
-                signal.global_state.world_state.node = global_state.node
-                self._add_world_state(signal.global_state)
+                # settlement: the parked findings' solves and the
+                # world-state plugins; nests in the round's host_exec
+                with obs.phase("settle"):
+                    check_potential_issues(global_state)
+                    signal.global_state.world_state.node = global_state.node
+                    self._add_world_state(signal.global_state)
             return []
 
         # resuming the caller frame

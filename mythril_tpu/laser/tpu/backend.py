@@ -30,6 +30,8 @@ import logging
 import os
 import threading
 import time
+from collections import deque
+from contextlib import contextmanager
 from typing import List, Optional
 
 import numpy as np
@@ -597,45 +599,60 @@ def _check_platform() -> None:
         )
 
 
+@contextmanager
+def _warm_stage(stage: str):
+    """One stage of the warmup: a ``warmup.<stage>`` span and its
+    seconds on ``myth_warmup_s{stage}``."""
+    t0 = time.perf_counter()
+    with obs.TRACER.span("warmup." + stage, tid="warmup"):
+        yield
+    obs.count(_cat.WARMUP_S, time.perf_counter() - t0, stage)
+
+
 def _warm_kernels(cfg: BatchConfig, want_stats: bool) -> None:
     from mythril_tpu.laser.tpu import ensure_compile_cache
     from mythril_tpu.laser.tpu.batch import batch_shapes, make_code_bank
 
-    # before anything compiles: a CPU fallback must not write XLA:CPU
-    # entries into the accelerator's (unsuffixed) cache directory
-    _check_platform()
-    ensure_compile_cache()
+    with _warm_stage("platform_upload"):
+        # before anything compiles: a CPU fallback must not write XLA:CPU
+        # entries into the accelerator's (unsuffixed) cache directory
+        _check_platform()
+        ensure_compile_cache()
 
-    np_batch = {
-        field: np.zeros(shape, dtype)
-        for field, (shape, dtype) in batch_shapes(cfg).items()
-    }
-    # seed one element per upload group so warmup compiles the same
-    # all-groups-present splitter variant (and tape bucket) the hot
-    # loop uses, plus the download flatteners
-    np_batch["memory"][0, 0] = 1
-    np_batch["storage_used"][0, 0] = True
-    np_batch["tape_len"][0] = 1
-    np_batch["tape_op"][0, 0] = 1
-    st = transfer.batch_to_device(np_batch, cfg)
-    cb = make_code_bank([b"\x00"], cfg.code_len, host_ops=(), freeze_errors=True)
-    out, _hist = _run_device(cb, st, cfg, want_stats=want_stats)
-    # _run_device warmed whichever loop the current policy selects
-    # (normally the fused megakernel). On the BACKGROUND-thread path
-    # also warm the synchronous slice loop: the breaker's half-open
-    # trial rounds run it, and a trial that pays the XLA compile
-    # inline would look exactly like the wedged device it is probing
-    # for. A synchronous caller (the test suite, warmup_device)
-    # blocks on this function, so it warms only the selected loop —
-    # the fallback compiles lazily if the degrade ladder ever runs.
+        np_batch = {
+            field: np.zeros(shape, dtype)
+            for field, (shape, dtype) in batch_shapes(cfg).items()
+        }
+        # seed one element per upload group so warmup compiles the same
+        # all-groups-present splitter variant (and tape bucket) the hot
+        # loop uses, plus the download flatteners
+        np_batch["memory"][0, 0] = 1
+        np_batch["storage_used"][0, 0] = True
+        np_batch["tape_len"][0] = 1
+        np_batch["tape_op"][0, 0] = 1
+        st = transfer.batch_to_device(np_batch, cfg)
+        cb = make_code_bank([b"\x00"], cfg.code_len, host_ops=(), freeze_errors=True)
+    # _run_device warms whichever loop the current policy selects
+    # (normally the fused megakernel)
+    with _warm_stage("fused_loop" if _fused_enabled() else "sync_loop"):
+        out, _hist = _run_device(cb, st, cfg, want_stats=want_stats)
+    # On the BACKGROUND-thread path also warm the synchronous slice
+    # loop: the breaker's half-open trial rounds run it, and a trial
+    # that pays the XLA compile inline would look exactly like the
+    # wedged device it is probing for. A synchronous caller (the test
+    # suite, warmup_device) blocks on this function, so it warms only
+    # the selected loop — the fallback compiles lazily if the degrade
+    # ladder ever runs.
     if WARMUP_ASYNC and _fused_enabled():
-        if want_stats:
-            out, _ = run_with_stats(
-                cb, default_env(), out, max_steps=DEVICE_SLICE_STEPS
-            )
-        else:
-            out = run(cb, default_env(), out, max_steps=DEVICE_SLICE_STEPS)
-    transfer.batch_to_host(out)
+        with _warm_stage("sync_loop"):
+            if want_stats:
+                out, _ = run_with_stats(
+                    cb, default_env(), out, max_steps=DEVICE_SLICE_STEPS
+                )
+            else:
+                out = run(cb, default_env(), out, max_steps=DEVICE_SLICE_STEPS)
+    with _warm_stage("download"):
+        transfer.batch_to_host(out)
     from mythril_tpu.smt import terms as _terms
 
     warm_formula = [_terms.bool_eq(_terms.bv_var("!warmup", 8), _terms.bv_const(1, 8))]
@@ -643,13 +660,14 @@ def _warm_kernels(cfg: BatchConfig, want_stats: bool) -> None:
     # feasibility flip budget (SOLVE_FLIPS — flips is a static
     # argnum, so a different budget is a different compile) at both
     # batch-ladder steps (remainder chunks and full chunks)
-    solver_cache.warm_device(
-        [warm_formula] * MIN_DEVICE_SOLVE_BATCH, flips=SOLVE_FLIPS
-    )
-    if not transfer.monomorphic():
+    with _warm_stage("solver_buckets"):
         solver_cache.warm_device(
-            [warm_formula] * solver_jax.MAX_BATCH, flips=SOLVE_FLIPS
+            [warm_formula] * MIN_DEVICE_SOLVE_BATCH, flips=SOLVE_FLIPS
         )
+        if not transfer.monomorphic():
+            solver_cache.warm_device(
+                [warm_formula] * solver_jax.MAX_BATCH, flips=SOLVE_FLIPS
+            )
 
 
 # lockstep steps between rebalance opportunities on a multi-device mesh
@@ -753,6 +771,47 @@ def _fused_steps_per_round() -> int:
 _fused_round_cost_s = [0.0]
 
 
+def _note_fused_round_cost(sample: float) -> None:
+    prev = _fused_round_cost_s[0]
+    _fused_round_cost_s[0] = sample if not prev else 0.5 * prev + 0.5 * sample
+
+
+class _PhaseWalls:
+    """The adaptive-K controller's numerator: a bounded window of the
+    recent walls of the host phases one sync waits on. Kept here, not
+    read from telemetry, so switching metrics off cannot change K."""
+
+    PHASES = ("host_exec", "lift", "solve")
+    CAP = 4096
+
+    def __init__(self) -> None:
+        self._walls = {p: deque(maxlen=self.CAP) for p in self.PHASES}
+
+    def add(self, phase: str, wall: float) -> None:
+        self._walls[phase].append(wall)
+
+    def p95(self, phase: str) -> Optional[float]:
+        walls = sorted(self._walls[phase])
+        if not walls:
+            return None
+        return walls[min(len(walls) - 1, int(round(0.95 * (len(walls) - 1))))]
+
+
+_HOST_WALLS = _PhaseWalls()
+
+
+@contextmanager
+def _host_phase(name: str, pid: int, **args):
+    """``obs.phase`` for a phase the adaptive-K controller reads: its
+    wall also feeds ``_HOST_WALLS``, telemetry on or off."""
+    t0 = time.perf_counter()
+    try:
+        with obs.phase(name, pid=pid, **args):
+            yield
+    finally:
+        _HOST_WALLS.add(name, time.perf_counter() - t0)
+
+
 def _fused_enabled() -> bool:
     mode = os.environ.get("MYTHRIL_TPU_FUSED", FUSED_MODE).lower()
     if mode == "off":
@@ -792,8 +851,8 @@ def _pick_fused_k() -> int:
             log.warning("bad MYTHRIL_TPU_FUSED_K=%r ignored", env_k)
     cost = _fused_round_cost_s[0]
     host = 0.0
-    for ph in ("host_exec", "lift", "solve"):
-        v = _cat.ROUND_PHASE_S.percentile(95, ph)
+    for ph in _PhaseWalls.PHASES:
+        v = _HOST_WALLS.p95(ph)
         if v:
             host += v
     if not cost or not host:
@@ -1002,6 +1061,7 @@ def _run_mesh_fused(
         if with_solve
         else None
     )
+    steps_per_round = _fused_steps_per_round()
     totals = {
         "k": k,
         "rounds": 0,
@@ -1012,6 +1072,7 @@ def _run_mesh_fused(
         "pruned_static": 0,
         "inloop_kills": 0,
         "device_wall_s": 0.0,
+        "lane_slots": 0,
         "n_shards": n_shards,
         "steal_events": 0,
         "steal_lanes": 0,
@@ -1028,13 +1089,17 @@ def _run_mesh_fused(
                 dispatch = min(dispatch, max(1, int(remaining / cost)))
         _cat.DEVICE_SLICES_TOTAL.inc()
         t0 = time.time()
+        span = obs.TRACER.begin(
+            "fused_super_round", tid="device", max_rounds=int(dispatch),
+            steps_per_round=steps_per_round, shards=n_shards,
+        )
         fo = megakernel.run_fused_mesh(
             mesh,
             cb,
             env,
             st,
             max_rounds=dispatch,
-            steps_per_round=_fused_steps_per_round(),
+            steps_per_round=steps_per_round,
             with_stats=want_stats,
             with_solve=with_solve,
             pool=pool,
@@ -1042,8 +1107,13 @@ def _run_mesh_fused(
         st = fo.st
         stats = megakernel.decode_mesh_info(fo.info, n_shards)  # one fetch
         wall = time.time() - t0
+        obs.TRACER.end(
+            span, rounds=stats.rounds, n_alive=stats.n_alive,
+            n_running=stats.n_running,
+        )
         totals["syncs"] += 1
         totals["rounds"] += stats.rounds
+        totals["lane_slots"] += stats.rounds * steps_per_round * st.pc.shape[0]
         totals["k_samples"].append(stats.rounds)
         totals["pruned_lanes"] += stats.pruned_lanes
         totals["pruned_steps"] += stats.pruned_steps
@@ -1073,18 +1143,7 @@ def _run_mesh_fused(
         if want_stats:
             hist = fo.hist if hist is None else hist + fo.hist
         if stats.rounds:
-            sample = wall / stats.rounds
-            prev = _fused_round_cost_s[0]
-            _fused_round_cost_s[0] = (
-                sample if not prev else 0.5 * prev + 0.5 * sample
-            )
-            for _ in range(stats.rounds):
-                _cat.ROUND_PHASE_S.observe(sample, "device_round_iter")
-                obs.TRACER.cut(
-                    "fused_round", "device_round_iter", rounds=stats.rounds,
-                    shards=n_shards,
-                )
-            obs.TRACER.end_cut("fused_round")
+            _note_fused_round_cost(wall / stats.rounds)
         rounds_left -= max(1, stats.rounds)
         resumed = False
         if bridge is not None:
@@ -1131,6 +1190,7 @@ def _run_device_fused(cb, st, cfg, want_stats=False, deadline=None, bridge=None)
         if with_solve
         else None
     )
+    steps_per_round = _fused_steps_per_round()
     totals = {
         "k": k,
         "rounds": 0,
@@ -1141,6 +1201,7 @@ def _run_device_fused(cb, st, cfg, want_stats=False, deadline=None, bridge=None)
         "pruned_static": 0,
         "inloop_kills": 0,
         "device_wall_s": 0.0,
+        "lane_slots": 0,
     }
     while rounds_left > 0:
         dispatch = rounds_left
@@ -1155,12 +1216,17 @@ def _run_device_fused(cb, st, cfg, want_stats=False, deadline=None, bridge=None)
                 dispatch = min(dispatch, max(1, int(remaining / cost)))
         _cat.DEVICE_SLICES_TOTAL.inc()
         t0 = time.time()
+        # dispatch to fetch: the span covers the host's wait for the device
+        span = obs.TRACER.begin(
+            "fused_super_round", tid="device", max_rounds=int(dispatch),
+            steps_per_round=steps_per_round,
+        )
         fo = megakernel.run_fused(
             cb,
             default_env(),
             st,
             max_rounds=dispatch,
-            steps_per_round=_fused_steps_per_round(),
+            steps_per_round=steps_per_round,
             with_stats=want_stats,
             with_solve=with_solve,
             pool=pool,
@@ -1168,8 +1234,13 @@ def _run_device_fused(cb, st, cfg, want_stats=False, deadline=None, bridge=None)
         st = fo.st
         stats = megakernel.decode_info(fo.info)  # the one blocking fetch
         wall = time.time() - t0
+        obs.TRACER.end(
+            span, rounds=stats.rounds, n_alive=stats.n_alive,
+            n_running=stats.n_running,
+        )
         totals["syncs"] += 1
         totals["rounds"] += stats.rounds
+        totals["lane_slots"] += stats.rounds * steps_per_round * st.pc.shape[0]
         totals["k_samples"].append(stats.rounds)
         totals["pruned_lanes"] += stats.pruned_lanes
         totals["pruned_steps"] += stats.pruned_steps
@@ -1186,22 +1257,7 @@ def _run_device_fused(cb, st, cfg, want_stats=False, deadline=None, bridge=None)
         if want_stats:
             hist = fo.hist if hist is None else hist + fo.hist
         if stats.rounds:
-            sample = wall / stats.rounds
-            prev = _fused_round_cost_s[0]
-            _fused_round_cost_s[0] = (
-                sample if not prev else 0.5 * prev + 0.5 * sample
-            )
-            # S3: the round_phase histogram stays meaningful under
-            # fusion — one synthetic per-round observation per fused
-            # iteration under its own label, so the super-round's
-            # "device_round" phase keeps its true wall time and the
-            # per-round cost stays queryable
-            for _ in range(stats.rounds):
-                _cat.ROUND_PHASE_S.observe(sample, "device_round_iter")
-                obs.TRACER.cut(
-                    "fused_round", "device_round_iter", rounds=stats.rounds
-                )
-            obs.TRACER.end_cut("fused_round")
+            _note_fused_round_cost(wall / stats.rounds)
         rounds_left -= max(1, stats.rounds)
         resumed = False
         if bridge is not None:
@@ -1291,6 +1347,7 @@ def filter_feasible(states: List[GlobalState]) -> List[GlobalState]:
                 if any(m is not None for m in interval_seeds)
                 else None
             ),
+            use="frontier",
         )
         for s, verdict in zip(undecided, verdicts):
             s.world_state.constraints.seed_feasibility(
@@ -1384,7 +1441,8 @@ def _triage_lazy_screens(states: List[GlobalState]) -> None:
         # host solve — but memoized verdicts from the frontier path and
         # earlier rounds short-circuit here for free
         verdicts = solver_cache.GLOBAL.decide_batch(
-            sets, use_device=True, flips=SOLVE_FLIPS, host_fallback=False
+            sets, use_device=True, flips=SOLVE_FLIPS, host_fallback=False,
+            use="triage",
         )
     except Exception as e:  # pragma: no cover - device issues degrade
         log.warning("lazy screen triage failed: %s", e)
@@ -1546,7 +1604,7 @@ def exec_batch(laser, track_gas=False) -> Optional[List[GlobalState]]:
         # exactly as in the host loop (reference svm.py exec).
         pending = list(laser.strategy)
         produced: List[tuple] = []  # (state, new_states, op_code)
-        with obs.phase("host_exec", pid=_pid, states=len(pending)):
+        with _host_phase("host_exec", _pid, states=len(pending)):
             for global_state in pending:
                 try:
                     new_states, op_code = laser.execute_state(global_state)
@@ -1562,7 +1620,7 @@ def exec_batch(laser, track_gas=False) -> Optional[List[GlobalState]]:
         engaged = strategy.engaged()
         if engaged:
             # feasibility for the whole successor frontier in one call
-            with obs.phase("solve", pid=_pid):
+            with _host_phase("solve", _pid):
                 filter_feasible(
                     [s for _, states, _ in produced for s in states]
                 )
@@ -1762,6 +1820,7 @@ def exec_batch(laser, track_gas=False) -> Optional[List[GlobalState]]:
             strategy.ss_drains += bridge.ss_drain_count
             if fused:
                 _steps += fused["pruned_steps"]
+                obs.count(_cat.DEVICE_LANE_SLOTS_TOTAL, fused["lane_slots"], pid=_pid)
                 strategy.static_pruned_lanes += fused["pruned_static"]
                 strategy.device_pruned_lanes += fused["pruned_lanes"]
                 strategy.in_loop_unsat_kills += fused.get("inloop_kills", 0)
@@ -1782,7 +1841,7 @@ def exec_batch(laser, track_gas=False) -> Optional[List[GlobalState]]:
             )
             fused_pv = None
         strategy.device_steps_retired += _steps
-        _cat.DEVICE_STEPS_TOTAL.inc(_steps)
+        obs.count(_cat.DEVICE_STEPS_TOTAL, _steps, pid=_pid)
         strategy.static_pruned_lanes += int(
             np.asarray(out.static_pruned)[own_alive].sum()
         )
@@ -1856,7 +1915,7 @@ def exec_batch(laser, track_gas=False) -> Optional[List[GlobalState]]:
 
         _pi.LAZY_SCREEN = True
         try:
-            with obs.phase("lift", pid=_pid):
+            with _host_phase("lift", _pid):
                 for lane in range(own_alive.shape[0]):
                     if not own_alive[lane]:
                         continue
@@ -1880,7 +1939,7 @@ def exec_batch(laser, track_gas=False) -> Optional[List[GlobalState]]:
             _pi.LAZY_SCREEN = False
         with obs.phase("triage", pid=_pid):
             _triage_lazy_screens(resumed_states)
-        with obs.phase("solve", pid=_pid):
+        with _host_phase("solve", _pid):
             feasible = filter_feasible(resumed_states)
         laser.work_list.extend(_apply_loop_bound(laser, feasible))
         # device-born forks add to the explored-state count — including

@@ -71,7 +71,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from mythril_tpu import obs
 from mythril_tpu.laser.tpu import inloop_solve
 from mythril_tpu.laser.tpu import mesh as mesh_lib
 from mythril_tpu.laser.tpu.batch import (
@@ -270,25 +269,20 @@ def run_fused(
 ) -> FusedOut:
     """Dispatch one fused super-round (up to ``max_rounds`` device
     rounds without a host sync). The caller owns the single host fetch
-    of ``out.info`` — nothing here blocks on device results."""
+    of ``out.info`` — nothing here blocks on device results — and the
+    ``fused_super_round`` span, which runs from dispatch to that fetch."""
     if pool is None:
         pool = inloop_solve.empty_pool()  # noqa: clause-free pool, sound anywhere
-    with obs.TRACER.span(
-        "fused_super_round",
-        tid="device",
-        max_rounds=int(max_rounds),
+    return _fused_impl(
+        cb,
+        env,
+        st,
+        jnp.asarray(int(max_rounds), I32),
+        pool,
         steps_per_round=steps_per_round,
-    ):
-        return _fused_impl(
-            cb,
-            env,
-            st,
-            jnp.asarray(int(max_rounds), I32),
-            pool,
-            steps_per_round=steps_per_round,
-            with_stats=with_stats,
-            with_solve=bool(with_solve),  # noqa: static python arg, not a tracer
-        )
+        with_stats=with_stats,
+        with_solve=bool(with_solve),  # noqa: static python arg, not a tracer
+    )
 
 
 def decode_info(info) -> FusedStats:
@@ -472,12 +466,5 @@ def run_fused_mesh(
         )
     if pool is None:
         pool = inloop_solve.empty_pool()  # noqa: clause-free pool, sound anywhere
-    with obs.TRACER.span(
-        "fused_super_round",
-        tid="device",
-        max_rounds=int(max_rounds),
-        steps_per_round=steps_per_round,
-        shards=n,
-    ):
-        fn = _mesh_kernel(mesh, steps_per_round, bool(with_stats), bool(with_solve))  # noqa: host-side cache key normalization
-        return fn(cb, env, st, jnp.asarray(int(max_rounds), I32), pool)
+    fn = _mesh_kernel(mesh, steps_per_round, bool(with_stats), bool(with_solve))  # noqa: host-side cache key normalization
+    return fn(cb, env, st, jnp.asarray(int(max_rounds), I32), pool)

@@ -815,9 +815,14 @@ class SolverCache:
         host_fallback: bool = True,
         static_unsat: Optional[Sequence[bool]] = None,
         interval_seeds: Optional[Sequence] = None,
+        use: str = "frontier",
     ) -> List[Optional[bool]]:
         """Decide a frontier of constraint sets: memo -> device batch ->
         inline quick host check -> async pool.
+
+        ``use`` labels the verdict counts (``myth_solver_batch_queries_
+        total``, ``myth_solver_outcomes_total``): ``frontier`` for the
+        round's feasibility filter, ``triage`` for the lazy screens.
 
         Returns True (feasible) / False (infeasible) / None (unknown —
         the caller should treat the lane as possible; the async pool
@@ -857,11 +862,34 @@ class SolverCache:
         inline quick check runs only when the device did NOT run
         (pre-warmup / sub-floor frontiers): there it is the only
         pruning the frontier gets."""
+        n = len(sets)
+        how: List[str] = ["unknown"] * n
+        with obs.TRACER.span("decide_batch", tid="solve", n=n, use=use):
+            verdicts = self._decide_batch(
+                sets, use_device, flips, hints, host_fallback, static_unsat,
+                interval_seeds, how,
+            )
+        if not n:
+            return verdicts
+        obs.count(_cat.SOLVER_BATCH_QUERIES_TOTAL, n, use)
+        tally: Dict[str, int] = {}
+        for i, verdict in enumerate(verdicts):
+            # a cached UNKNOWN, or a host check that failed, decided nothing
+            outcome = how[i] if verdict is not None else "unknown"
+            tally[outcome] = tally.get(outcome, 0) + 1
+        for outcome, k in tally.items():
+            obs.count(_cat.SOLVER_OUTCOMES_TOTAL, k, use, outcome)
+        return verdicts
+
+    def _decide_batch(
+        self, sets, use_device, flips, hints, host_fallback, static_unsat,
+        interval_seeds, how,
+    ) -> List[Optional[bool]]:
+        """decide_batch's body; ``how[i]`` records what answered set i."""
         from mythril_tpu.laser.tpu import solver_jax
 
         t0 = time.monotonic()
         n = len(sets)
-        _span = obs.TRACER.begin("decide_batch", tid="solve", n=n)
         self._count("queries", n)
         if n:
             self._count("round_batches")
@@ -883,6 +911,7 @@ class SolverCache:
                 # record the UNSAT so supersets subsume without re-proof
                 verdicts[i] = False
                 decided[i] = True
+                how[i] = "static"
                 self._count("static_unsat_seeds")
                 self.record(cs, UNSAT)
                 continue
@@ -908,6 +937,7 @@ class SolverCache:
                     if oc.verdict is False:
                         verdicts[i] = False
                         decided[i] = True
+                        how[i] = "rewrite"
                         self._count("rewrite_discharged")
                         # the singleton core is a MAXIMAL subsumption
                         # seed: any superset of {core} is UNSAT
@@ -921,6 +951,7 @@ class SolverCache:
                     if oc.verdict is True:
                         verdicts[i] = True
                         decided[i] = True
+                        how[i] = "rewrite"
                         self._count("rewrite_discharged")
                         continue
             code, key, digest = self._lookup(work[i])
@@ -936,6 +967,7 @@ class SolverCache:
                     if model is not None and _rw.try_witness(work[i], model):
                         verdicts[i] = True
                         decided[i] = True
+                        how[i] = "witness"
                         self._count("assumption_reuse")
                         self.record(
                             work[i],
@@ -949,6 +981,7 @@ class SolverCache:
                 pending.append(i)
                 continue
             decided[i] = True
+            how[i] = "memo"
             if code == SAT:
                 verdicts[i] = True
             elif code == UNSAT:
@@ -996,6 +1029,7 @@ class SolverCache:
                     continue
                 verdicts[i] = v
                 decided[i] = True
+                how[i] = "device"
                 self._count("device_decided")
                 fp = None
                 if hints is not None and hints[i]:
@@ -1036,6 +1070,7 @@ class SolverCache:
                     continue
                 if code == SAT:
                     verdicts[i] = True
+                    how[i] = "host"
                     self._count("host_decided")
                     self.record(
                         work[i], SAT, key=keys[i],
@@ -1043,6 +1078,7 @@ class SolverCache:
                     )
                 elif code == UNSAT:
                     verdicts[i] = False
+                    how[i] = "host"
                     self._count("host_decided")
                     self.record(
                         work[i], UNSAT, key=keys[i],
@@ -1061,7 +1097,6 @@ class SolverCache:
                             cancel_event=cancel_event,
                         )
         self._add_time(time.monotonic() - t0)
-        obs.TRACER.end(_span)
         return verdicts
 
     @staticmethod

@@ -15,6 +15,9 @@
 context manager that both records a tracer span (when tracing is on)
 and observes the duration into the ``myth_round_phase_s`` histogram
 (when metrics are on) — each layer stays independently switchable.
+:func:`count` is its counterpart for counts: a catalog counter
+increment (when metrics are on) that is also a counter event in the
+trace (when tracing is on).
 """
 
 import time
@@ -30,6 +33,7 @@ __all__ = [
     "REGISTRY",
     "TRACER",
     "catalog",
+    "count",
     "metrics",
     "phase",
     "trace",
@@ -52,3 +56,12 @@ def phase(name: str, pid: int = 0, **args):
         if metering:
             catalog.ROUND_PHASE_S.observe(time.perf_counter() - t0, name)
         TRACER.end(token)
+
+
+def count(counter, amount: float, *labels: str, **args) -> None:
+    """Add ``amount`` to a catalog counter (``labels`` its label
+    values) and, while tracing, record the increment as a counter event
+    (``args`` ride along; ``pid`` names the job row)."""
+    counter.inc(amount, *labels)
+    if TRACER.enabled:
+        TRACER.count(counter.name, amount, dict(zip(counter.labelnames, labels)), **args)

@@ -42,9 +42,44 @@ DEVICE_STEPS_TOTAL = REGISTRY.counter(
 DEVICE_SLICES_TOTAL = REGISTRY.counter(
     "myth_device_slices_total", "jitted step-kernel slices dispatched"
 )
+# the fused loop's fixed-shape work: rounds x steps_per_round x lanes
+# per dispatch (all shards on the mesh path); lane-steps retired over
+# this is the share of the loop's work that advanced a lane
+DEVICE_LANE_SLOTS_TOTAL = REGISTRY.counter(
+    "myth_device_lane_slots_total",
+    "lane-step slots the fused device loop issued",
+)
+# compile-and-run seconds of each stage of the blocking device warmup
+# (backend._warm_kernels)
+WARMUP_S = REGISTRY.counter(
+    "myth_warmup_s", "device warmup seconds", labelnames=("stage",)
+)
 SOLVER_BATCHES_TOTAL = REGISTRY.counter(
     "myth_solver_batches_total",
     "device feasibility kernel batches dispatched",
+)
+# the frontier and triage verdict path (solver_cache.decide_batch):
+# queries by use (frontier = backend.filter_feasible, triage =
+# backend._triage_lazy_screens) and what answered each one — memo,
+# rewrite, static, witness (ancestor model reuse), device, host — or
+# unknown; the outcomes of one use sum to its queries
+SOLVER_BATCH_QUERIES_TOTAL = REGISTRY.counter(
+    "myth_solver_batch_queries_total",
+    "constraint sets handed to decide_batch",
+    labelnames=("use",),
+)
+SOLVER_OUTCOMES_TOTAL = REGISTRY.counter(
+    "myth_solver_outcomes_total",
+    "decide_batch queries by what decided them, or unknown",
+    labelnames=("use", "outcome"),
+)
+# every native SAT solve on the host (IncrementalCore.solve_checked),
+# whoever asked: settlement, detectors, the frontier's host fallback,
+# the rewrite pass's assumption probes
+HOST_SAT_SOLVES_TOTAL = REGISTRY.counter(
+    "myth_host_sat_solves_total",
+    "host SAT solves by result",
+    labelnames=("result",),
 )
 # real blast volume, accumulated at CNF-compile time (solver_jax
 # .check_batch): the denominator of the stage-3 rewrite pass's clause

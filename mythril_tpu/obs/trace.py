@@ -18,7 +18,17 @@ Model (Chrome trace-event format, Perfetto / chrome://tracing loadable):
 * phase spans are ``ph: "X"`` complete events (ts/dur in microseconds);
 * marks are ``ph: "i"`` instant events (``dur`` kept at 0 so every
   event carries the full ``ph/ts/dur/pid/tid/name`` key set);
+* counts are ``ph: "C"`` counter events carrying one increment of a
+  catalog counter (``obs.count``), so summing them over a slice of the
+  trace gives that slice's counts;
 * rows are named via ``ph: "M"`` metadata events at export.
+
+While the tracer is on, every span (``span``, ``begin``/``end`` and so
+``obs.phase``) also opens a ``jax.profiler.TraceAnnotation`` of the
+same name, so a profiler session records the program's phases beside
+the device ops, on the profiler's own clock. jax is used only when
+something already imported it: the fleet gateway imports this module
+and stays device-free.
 
 Rounds are *cut* spans: :meth:`Tracer.cut` closes the previous span on
 a track and opens the next, so the round span survives the loop body's
@@ -34,6 +44,7 @@ rounds before the oldest drop; drops are counted).
 """
 
 import json
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -46,6 +57,7 @@ _DEFAULT_CAPACITY = 262144
 # event tuples: (kind, name, tid, pid, ts_s, dur_s, args)
 _SPAN = "X"
 _MARK = "i"
+_COUNT = "C"
 
 
 class _NullSpan:
@@ -59,6 +71,22 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+
+def _annotate(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name``, or
+    None where jax is not loaded (nothing here imports it)."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    annotation = profiler.TraceAnnotation(name)
+    annotation.__enter__()
+    return annotation
+
+
+def _close(annotation) -> None:
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
 
 
 class Tracer:
@@ -120,9 +148,11 @@ class Tracer:
         self, name: str, tid: str, pid: int, args: dict
     ) -> Iterator[None]:
         t0 = time.perf_counter()
+        annotation = _annotate(name)
         try:
             yield
         finally:
+            _close(annotation)
             t1 = time.perf_counter()
             self._push((_SPAN, name, tid, pid, t0, t1 - t0, args))
 
@@ -131,13 +161,29 @@ class Tracer:
         token (or None when disabled)."""
         if not self.enabled:
             return None
-        return (name, tid or name, pid, time.perf_counter(), args)
+        t0 = time.perf_counter()
+        return (name, tid or name, pid, t0, args, _annotate(name))
 
-    def end(self, token) -> None:
-        if token is None or not self.enabled:
+    def end(self, token, **args) -> None:
+        """Close a :meth:`begin` span; ``args`` join the begin's (values
+        known only at the end, such as what a dispatch returned)."""
+        if token is None:
             return
-        name, tid, pid, t0, args = token
-        self._push((_SPAN, name, tid, pid, t0, time.perf_counter() - t0, args))
+        name, tid, pid, t0, begin_args, annotation = token
+        _close(annotation)
+        if not self.enabled:
+            return
+        if args:
+            begin_args = dict(begin_args, **args)
+        self._push((_SPAN, name, tid, pid, t0, time.perf_counter() - t0, begin_args))
+
+    def count(self, name: str, amount: float, labels: dict, pid: int = 0, **args):
+        """Counter event: one increment of the catalog counter ``name``
+        (``labels`` its label values), at this moment."""
+        if not self.enabled:
+            return
+        self._push((_COUNT, name, name, pid, time.perf_counter(), 0.0,
+                    dict(args, amount=amount, labels=labels)))
 
     def mark(self, name: str, tid: str = "incident", pid: int = 0, **args):
         """Instant event (robustness incidents, fault injections)."""
@@ -230,6 +276,11 @@ class Tracer:
             }
             if kind == _MARK:
                 event["s"] = "t"
+            if kind == _COUNT:
+                # a counter track per label set; Chrome plots numeric
+                # args only, so the increment is the one series
+                event["name"] = _series(name, args["labels"])
+                args = {"value": args["amount"]}
             if args:
                 event["args"] = args
             out.append(event)
@@ -255,6 +306,12 @@ class Tracer:
         with open(path, "w") as fh:
             json.dump(trace, fh)
         return len(trace["traceEvents"])
+
+
+def _series(name: str, labels: dict) -> str:
+    if not labels:
+        return name
+    return "%s{%s}" % (name, ",".join('%s="%s"' % kv for kv in labels.items()))
 
 
 def _meta(kind: str, pid: int, tid: int, label: str) -> Dict[str, Any]:

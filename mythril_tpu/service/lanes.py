@@ -348,6 +348,11 @@ class LaneCoordinator:
                     req.packed = []
                 return
             self.device_retries += counters.device_retries
+            # the shared round's fused slots count once here; each job's
+            # exec_batch counts the lane-steps of its own lanes
+            fused = getattr(bridge, "fused_round_info", None)
+            if fused:
+                obs.count(_cat.DEVICE_LANE_SLOTS_TOTAL, fused["lane_slots"])
 
             resident = np.unique(
                 np.asarray(out.job_id)[np.asarray(out.alive)]

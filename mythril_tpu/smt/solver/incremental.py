@@ -19,6 +19,8 @@ import logging
 import time
 from typing import Dict, FrozenSet, List, Optional, Tuple
 
+from mythril_tpu import obs
+from mythril_tpu.obs import catalog as _cat
 from mythril_tpu.smt import terms
 from mythril_tpu.smt.solver import pysat
 from mythril_tpu.smt.solver.bitblast import Blaster
@@ -33,6 +35,9 @@ log = logging.getLogger(__name__)
 # Safety valve: when the accumulated clause database outgrows this, the core
 # is rebuilt lazily (caches repopulate on demand from the live term DAG).
 CLAUSE_LIMIT = 40_000_000
+
+# myth_host_sat_solves_total's result label
+_RESULTS = {pysat.SAT: "sat", pysat.UNSAT: "unsat"}
 
 
 class LazyCongruenceEliminator(TheoryEliminator):
@@ -234,7 +239,21 @@ class IncrementalCore:
         ``timeout_ms`` bounds the WHOLE loop, not each round: a repair
         loop of N rounds each granted the full budget overshot
         feasibility checks ~5x (profiled: 100ms budgets averaging 540ms
-        per is_possible on multiplier-heavy constraints)."""
+        per is_possible on multiplier-heavy constraints).
+
+        Every host SAT solve passes here, so this is the ``host_sat``
+        phase and the ``myth_host_sat_solves_total`` count; it nests in
+        whichever phase asked (host_exec, settle, triage, solve, lift)."""
+        with obs.phase("host_sat"):
+            code = self._solve_repaired(
+                lits, query_rws, timeout_ms, conflict_budget, max_repair_rounds
+            )
+        obs.count(_cat.HOST_SAT_SOLVES_TOTAL, 1, _RESULTS.get(code, "unknown"))
+        return code
+
+    def _solve_repaired(
+        self, lits, query_rws, timeout_ms, conflict_budget, max_repair_rounds
+    ) -> int:
         deadline = (
             time.monotonic() + timeout_ms / 1000.0 if timeout_ms else None
         )

@@ -76,9 +76,12 @@ def test_becstress_trace_schema_and_round_nesting(tmp_path):
     assert events, "traced analysis recorded no events"
     for event in events:
         assert REQUIRED_KEYS <= set(event.keys()), event
-        assert event["ph"] in ("X", "i", "M"), event
+        assert event["ph"] in ("X", "i", "C", "M"), event
         if event["ph"] != "M":
             assert event["ts"] >= 0 and event["dur"] >= 0, event
+        if event["ph"] == "C":
+            # a counter event carries one numeric increment
+            assert isinstance(event["args"]["value"], (int, float)), event
 
     rounds = sorted(
         (e for e in events if e["ph"] == "X" and e["name"] == "round"),
