@@ -10,7 +10,8 @@ Parity surface: mythril/analysis/module/modules/integer.py. Three stages:
   3. at transaction end every collected hazard is solved together with
      the path condition; satisfiable wraps become issues reported at the
      arithmetic instruction (with per-origin sat/unsat caching so shared
-     hazards are solved once).
+     hazards are solved once, and no solve at all for a site already
+     reported: the report keeps one issue per site).
 """
 
 import logging
@@ -18,12 +19,14 @@ from copy import copy
 from math import ceil, log2
 from typing import Set
 
+from mythril_tpu import obs
 from mythril_tpu.analysis import solver
 from mythril_tpu.analysis.module.base import DetectionModule, EntryPoint
 from mythril_tpu.analysis.report import Issue
 from mythril_tpu.analysis.swc_data import INTEGER_OVERFLOW_AND_UNDERFLOW
 from mythril_tpu.exceptions import UnsatError
 from mythril_tpu.laser.evm.state.annotation import StateAnnotation
+from mythril_tpu.obs import catalog as _cat
 from mythril_tpu.smt import (
     And,
     BVAddNoOverflow,
@@ -229,6 +232,9 @@ class IntegerArithmetics(DetectionModule):
             origin = hazard.origin_state
             if origin in self._origin_unsat:
                 continue
+            if self._reported(origin):
+                obs.count(_cat.SETTLE_SKIPPED_TOTAL, 1, "reported")
+                continue
             if origin not in self._origin_sat and not self._wrap_feasible(hazard):
                 continue
             try:
@@ -266,6 +272,7 @@ class IntegerArithmetics(DetectionModule):
                 origin in skip
                 or origin in self._origin_sat
                 or origin in self._origin_unsat
+                or self._reported(origin)
             ):
                 continue
             skip.add(origin)
@@ -280,6 +287,15 @@ class IntegerArithmetics(DetectionModule):
 
     def seed_prescreen(self, token, verdict: bool) -> None:
         (self._origin_sat if verdict else self._origin_unsat).add(token)
+
+    def _reported(self, origin) -> bool:
+        """Is the hazard's site already reported? Then its wrap and
+        witness solves decide nothing: the report keeps one issue per
+        site, and ``_report`` put the site in the cache with the first."""
+        return (
+            origin.environment.active_account.contract_name,
+            origin.get_current_instruction()["address"],
+        ) in self.cache
 
     def _wrap_feasible(self, hazard) -> bool:
         """Solve the wrap condition at its origin once per origin state."""

@@ -8,6 +8,7 @@ STATICCALL executed) nor could have moved value leaves the world exactly
 as it found it — exploring further transactions from that world state
 duplicates work, so the open state is dropped."""
 
+from mythril_tpu import obs
 from mythril_tpu.analysis import solver
 from mythril_tpu.exceptions import UnsatError
 from mythril_tpu.laser.evm.plugins.implementations.plugin_annotations import (
@@ -19,6 +20,7 @@ from mythril_tpu.laser.evm.state.global_state import GlobalState
 from mythril_tpu.laser.evm.transaction.transaction_models import (
     ContractCreationTransaction,
 )
+from mythril_tpu.obs import catalog as _cat
 from mythril_tpu.smt import UGT, symbol_factory
 
 MUTATING_OPS = ("SSTORE", "CALL", "STATICCALL")
@@ -58,7 +60,10 @@ class MutationPruner(LaserPlugin):
                 global_state.current_transaction, ContractCreationTransaction
             ):
                 return
-            if _value_transfer_possible(global_state):
-                return  # balances changed: the state is not clean
-            if not any(global_state.get_annotations(MutationAnnotation)):
+            # the annotation first: a mutated state is kept whatever
+            # the value-transfer solve would say, so it is not solved
+            if any(global_state.get_annotations(MutationAnnotation)):
+                obs.count(_cat.SETTLE_SKIPPED_TOTAL, 1, "mutated")
+                return
+            if not _value_transfer_possible(global_state):
                 raise PluginSkipWorldState

@@ -81,6 +81,15 @@ HOST_SAT_SOLVES_TOTAL = REGISTRY.counter(
     "host SAT solves by result",
     labelnames=("result",),
 )
+# transaction-end settlement solves not made because their outcome was
+# already decided: reason=reported, an integer hazard whose site is
+# already in the report; reason=mutated, the mutation pruner's
+# value-transfer solve on a state that wrote storage or called out
+SETTLE_SKIPPED_TOTAL = REGISTRY.counter(
+    "myth_settle_skipped_total",
+    "settlement solves skipped as already decided",
+    labelnames=("reason",),
+)
 # real blast volume, accumulated at CNF-compile time (solver_jax
 # .check_batch): the denominator of the stage-3 rewrite pass's clause
 # reduction claim (docs/REWRITE_PASS.md) — compare against a
